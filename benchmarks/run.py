@@ -942,9 +942,10 @@ def backend_latency():
     # valid tokens while the allocated page grows. The streaming kernel
     # runs ceil(occ / bk) chunks per lane — page-size independent — while
     # the dense gather path attends the whole masked page, O(page_len).
-    # Interpret-mode wall time tracks executed chunk count, so the
-    # *growth* of each curve across page sizes is meaningful even though
-    # absolute interpret latencies are not comparable to compiled jnp.
+    # The kernel runs compiled on TPU and interpreted elsewhere; either way
+    # its wall time tracks executed chunk count, so the *growth* of each
+    # curve across page sizes is meaningful, while absolute interpreted
+    # latencies are not comparable to compiled jnp.
     occ, pl_lanes = 64, 4
     h_kv, dh = cfg.n_kv_heads, cfg.hd
     paged_fixed_occ_us: dict = {}
@@ -962,7 +963,7 @@ def backend_latency():
         kern = lambda pages=pages, qh=qh, rows=rows, lens=lens, sc=sc: (
             paged_ops.ragged_paged_decode(
                 qh, rows, lens, kv=pages, scale=sc, use_pallas=True,
-                interpret=True, bk=32, buffers=2,
+                bk=32, buffers=2,
             ).block_until_ready()
         )
         ref = jax.jit(
@@ -975,12 +976,12 @@ def backend_latency():
             ref(pages, qh, rows, lens).block_until_ready()
         )
         paged_fixed_occ_us[str(W)] = interleaved_min(
-            {"kernel_interpret": kern, "gather_ref": refc}, reps=5
+            {"kernel": kern, "gather_ref": refc}, reps=5
         )
     occ_growth = {
         name: (paged_fixed_occ_us["1024"][name]
                / max(paged_fixed_occ_us["64"][name], 1e-9))
-        for name in ("kernel_interpret", "gather_ref")
+        for name in ("kernel", "gather_ref")
     }
 
     ratios = {
@@ -1024,7 +1025,7 @@ def backend_latency():
         f"pool I/O growth 64->1024: gather/scatter {io_growth_leg:.1f}x "
         f"vs fused row write {io_growth_fus:.1f}x; fixed-occupancy decode "
         f"growth 64->1024: ragged kernel "
-        f"{occ_growth['kernel_interpret']:.2f}x vs dense gather "
+        f"{occ_growth['kernel']:.2f}x vs dense gather "
         f"{occ_growth['gather_ref']:.2f}x -> BENCH_backends.json"
     )
 
@@ -1274,12 +1275,12 @@ def kernel_mxfp4_matmul_microbench():
     wq = mxlib.quantize_w(w)
     codes = mxlib.pack_codes(wq.codes.T).T
     exps = mxlib.exps_to_biased(wq.exps)
-    out = mm_ops.mxfp4_matmul(x, codes, exps, interpret=True)
+    out = mm_ops.mxfp4_matmul(x, codes, exps)
     ref = mm_ref.mxfp4_matmul_ref(x, codes, exps)
     err = float(
         jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)))
     )
-    return f"interpret-mode max err {err:.3e}; packed density 4.25 b/param"
+    return f"kernel max err {err:.3e}; packed density 4.25 b/param"
 
 
 @bench
@@ -1297,6 +1298,9 @@ def digital_attention_fidelity():
 def main(argv=None) -> None:
     import argparse
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run only benchmarks whose name contains this "

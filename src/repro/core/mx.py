@@ -53,7 +53,7 @@ def exp2i(e: jax.Array) -> jax.Array:
     for e in [-252, 252] (split into two factors to cover beyond the
     single-factor [-126, 127] range)."""
     e = jnp.asarray(e, jnp.int32)
-    h1 = jnp.clip(e // 2, -126, 127)
+    h1 = jnp.clip(e >> 1, -126, 127)  # floor(e / 2); Mosaic has no `//`
     h2 = jnp.clip(e - h1, -126, 127)
 
     def f(h):
@@ -209,6 +209,16 @@ def pack_codes(codes: jax.Array) -> jax.Array:
     return (lo | (hi << 4)).astype(jnp.uint8)
 
 
+def nibble_code2x(nib: jax.Array) -> jax.Array:
+    """int32 E2M1 nibbles -> int32 *code* values (``2 * fp4``), exact:
+    ``(-1)^s * (e == 0 ? m : (2 + m) << (e - 1))``. Shifts and selects
+    only, so Pallas kernels decode packed weights with it in-tile."""
+    m = nib & 1
+    e = (nib >> 1) & 3
+    c = jnp.where(e == 0, m, (2 + m) << jnp.maximum(e - 1, 0))
+    return jnp.where(((nib >> 3) & 1) == 1, -c, c)
+
+
 def unpack_codes(packed: jax.Array) -> jax.Array:
     lo = packed & 0x0F
     hi = (packed >> 4) & 0x0F
@@ -241,17 +251,12 @@ def _build_pair_table() -> np.ndarray:
 PAIR_TABLE = _build_pair_table()
 
 
-def unpack_pairs_bf16(packed: jax.Array, table: jax.Array | None = None
-                      ) -> jax.Array:
+def unpack_pairs_bf16(packed: jax.Array) -> jax.Array:
     """Packed uint8 nibble pairs [..., K//2] -> bf16 *code* values
     (``2 * fp4``) [..., K] through :data:`PAIR_TABLE`: one gather + one
     bitcast per byte, no shift/select chain. Element ``2i`` comes from the
-    low nibble of byte ``i`` (the :func:`pack_codes` layout). ``table``
-    lets Pallas kernels thread the table in as an operand (kernels cannot
-    capture array constants)."""
-    if table is None:
-        table = jnp.asarray(PAIR_TABLE)
-    pair = table[packed.astype(jnp.int32)]  # [..., K//2]
+    low nibble of byte ``i`` (the :func:`pack_codes` layout)."""
+    pair = jnp.asarray(PAIR_TABLE)[packed.astype(jnp.int32)]  # [..., K//2]
     u16 = jax.lax.bitcast_convert_type(pair, jnp.uint16)  # [..., K//2, 2] LE
     cb = jax.lax.bitcast_convert_type(u16, jnp.bfloat16)
     return cb.reshape(packed.shape[:-1] + (-1,))
@@ -326,6 +331,19 @@ def _fake_quant_impl(x: jax.Array, axis: int) -> jax.Array:
         xf = jnp.pad(xf, pad)
     shp = xf.shape
     xb = xf.reshape(shp[:a] + ((k + rem) // BLOCK, BLOCK) + shp[a + 1:])
+    amax = jnp.max(jnp.abs(xb), axis=a + 1, keepdims=True)
+    v = fake_quant_at(xb, amax).reshape(shp)
+    if rem:
+        v = jax.lax.slice_in_dim(v, 0, k, axis=a)
+    return v.astype(x.dtype)
+
+
+def fake_quant_at(x: jax.Array, amax: jax.Array) -> jax.Array:
+    """The quantize-dequantize chain of :func:`fake_quant` for f32 or bf16
+    ``x``, given each element's block amax (broadcastable to ``x``).
+    Callers that cannot reshape into 32-blocks (a Pallas kernel) find the
+    amax their own way."""
+    bf16 = x.dtype == jnp.bfloat16
 
     def field(t):  # biased IEEE exponent field (bf16 and f32 share it)
         if bf16:
@@ -338,20 +356,14 @@ def _fake_quant_impl(x: jax.Array, axis: int) -> jax.Array:
             return _bf16_pow2(f)
         return jax.lax.bitcast_convert_type(f << 23, jnp.float32)
 
-    ax = jnp.abs(xb)
-    amax = jnp.max(ax, axis=a + 1, keepdims=True)
     ebf = jnp.clip(field(amax), 2, 254)
-    y = ax * pow2(256 - ebf)
+    y = jnp.abs(x) * pow2(256 - ebf)
     e = jnp.clip(field(y) - 127, 0, EMAX_ELEM)
     q = jnp.rint(y * pow2(128 - e))
     q = q * pow2(126 + e)
-    q = jnp.minimum(q, jnp.asarray(FP4_MAX, xb.dtype))
+    q = jnp.minimum(q, jnp.asarray(FP4_MAX, x.dtype))
     scale = _bf16_pow2(ebf - 2) if bf16 else exp2i(ebf - 129)
-    v = jnp.where(xb < 0, -q, q) * scale  # q * 2^e_shared
-    v = v.reshape(shp)
-    if rem:
-        v = jax.lax.slice_in_dim(v, 0, k, axis=a)
-    return v.astype(x.dtype)
+    return jnp.where(x < 0, -q, q) * scale  # q * 2^e_shared
 
 
 def _fqa_fwd(x, axis):

@@ -230,8 +230,8 @@ class StagePipeline:
     microbatches, one jitted shard_map step.
 
     Built via :func:`build_lm_pipeline` / :func:`build_vit_pipeline`. The
-    embed front and the final-norm/head back run outside the shard_map
-    body on replicated params: the trunk step's HLO then contains *only*
+    embed front and the final-norm/head back run outside the trunk step,
+    replicated on every device: the trunk step's HLO then contains *only*
     the stage-to-stage ``collective-permute`` — the transfer guard that
     pins the weights-never-move invariant.
     """
@@ -303,8 +303,15 @@ class StagePipeline:
                 check_rep=False,
             )
         )
-        self._embed = jax.jit(embed_fn)
-        self._head = jax.jit(head_fn)
+        # front and back compute redundantly on every device, each in its
+        # own shard_map: XLA cannot partition a Pallas kernel (a cim or
+        # mxfp4 linear) over the mesh the replicated params live on
+        def replicated(fn):
+            return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P(), P()),
+                                     out_specs=P(), check_rep=False))
+
+        self._embed = replicated(embed_fn)
+        self._head = replicated(head_fn)
         self._stage_fn = stage_fn
         self._last_report: MeasuredReport | None = None
 
@@ -373,11 +380,19 @@ class StagePipeline:
         return rl.parse_collectives(self.step_hlo(batch), self.n_devices)
 
     def trunk_resident(self) -> bool:
-        """Every trunk leaf is sharded over the stage axis (placed once at
-        construction; nothing below ever re-places it)."""
+        """Every trunk leaf is sharded over the stage axis, and stage
+        ``s``'s slice sits only on the devices of mesh column ``s`` (placed
+        once at construction; nothing below ever re-places it)."""
         def ok(a):
             spec = a.sharding.spec
-            return len(spec) > 0 and spec[0] == "stage"
+            if not (len(spec) > 0 and spec[0] == "stage"):
+                return False
+            for sh in a.addressable_shards:
+                s = sh.index[0].start or 0
+                if (sh.data.shape[0] != 1
+                        or sh.device not in self.mesh.devices[:, s]):
+                    return False
+            return True
 
         return all(ok(a) for a in jax.tree.leaves(self.trunk))
 

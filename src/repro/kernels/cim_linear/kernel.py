@@ -81,7 +81,9 @@ def _kernel(
         a1_ref[...] = jnp.zeros_like(a1_ref)
         a2_ref[...] = jnp.zeros_like(a2_ref)
 
-    e_n = cal_ref[0, 0].astype(jnp.int32)
+    # E_N broadcast to a vector: Mosaic bitcasts vectors only, and the
+    # store's 2^E_N scale is built by exponent-field bitcast
+    e_n = jnp.full((1, 1), cal_ref[0, 0]).astype(jnp.int32)
     fs = cal_ref[0, 1]
     xt = x_ref[...].astype(jnp.float32)  # [bm, bk] raw activations
 

@@ -10,7 +10,12 @@ import jax.numpy as jnp
 from repro.core import cim as cimlib
 from repro.core import mx as mxlib
 from repro.kernels.cim_linear.kernel import cim_linear_kernel
-from repro.kernels.mxfp4_matmul.ops import _round_up, pick_bm
+from repro.kernels.mxfp4_matmul.ops import (
+    _round_up,
+    pick_bk,
+    pick_bm,
+    pick_bn,
+)
 from repro.obs.profile import profiled_call
 
 
@@ -34,13 +39,6 @@ def cim_linear(
     pm = _round_up(m, bm) - m
     if pm:
         xm = jnp.pad(xm, ((0, pm), (0, 0)))
-    bn, bk = 128, 128
-    bn = min(bn, n)
-    while n % bn:
-        bn //= 2
-    bk = min(bk, k)
-    while k % bk or bk % 32:
-        bk //= 2
     cal = jnp.array(
         [[jnp.asarray(calib.e_n, jnp.float32), calib.adc_fs]], jnp.float32
     )
@@ -48,7 +46,7 @@ def cim_linear(
         "cim_linear", obs,
         lambda: cim_linear_kernel(
             xm, w.codes, w.exps, cal,
-            bm=bm, bn=bn, bk=max(bk, 32), cm=cfg.cm_bits,
+            bm=bm, bn=pick_bn(n), bk=pick_bk(k), cm=cfg.cm_bits,
             adc_bits=cfg.adc_bits, two_pass=cfg.two_pass,
             interpret=interpret,
         ),

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import mx as mxlib
 from repro.kernels import default_interpret
 
 
@@ -29,14 +30,11 @@ def _decode_tile(codes_u8: jax.Array, exps_u8: jax.Array) -> jax.Array:
     jnp.exp2 which lowers to exp(x*ln2)).
     """
     kk2, bn = codes_u8.shape
-    lo = (codes_u8 & 0x0F).astype(jnp.int32)
-    hi = ((codes_u8 >> 4) & 0x0F).astype(jnp.int32)
+    c = codes_u8.astype(jnp.int32)  # Mosaic has no uint8 logical shift
+    lo = c & 0x0F
+    hi = (c >> 4) & 0x0F
     nib = jnp.stack([lo, hi], axis=1).reshape(kk2 * 2, bn)
-    s = (nib >> 3) & 1
-    e = (nib >> 1) & 3
-    m = nib & 1
-    code2x = jnp.where(e == 0, m, (2 + m) << jnp.maximum(e - 1, 0))
-    code2x = jnp.where(s == 1, -code2x, code2x).astype(jnp.float32)
+    code2x = mxlib.nibble_code2x(nib).astype(jnp.float32)
     scale = jax.lax.bitcast_convert_type(
         exps_u8.astype(jnp.int32) << 23, jnp.float32
     )  # [bk//32, bn] == 2^(e-127)

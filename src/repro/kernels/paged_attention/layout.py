@@ -2,9 +2,11 @@
 flash-decode kernel, its jnp reference, and the attention decode path.
 
 Raw pages interleave K and V per KV head so that *one* HBM->VMEM async
-copy per (lane, head, token-chunk) streams both SDPA operands::
+copy per (lane, token-chunk) streams both SDPA operands of every head::
 
-    kv [B, W, 2*Hkv, Dh]      K_h = kv[:, :, 2h]   V_h = kv[:, :, 2h+1]
+    kv [B, W, 2*Hkv, Dl]      K_h = kv[:, :, 2h]   V_h = kv[:, :, 2h+1]
+
+where ``Dl = lane_dim(Dh)`` pads head_dim to whole 128-lane rows (zeros).
 
 Quantized-resident pools (hybrid / fully-digital MXFP4 SDPA) mirror the
 pages in the MXFP4 code domain. Codes are nibble-packed along head_dim
@@ -33,10 +35,18 @@ import jax.numpy as jnp
 from repro.core import mx as mxlib
 
 BLOCK = mxlib.BLOCK
+LANES = 128
 
 
 def padded_head_dim(hd: int) -> int:
     return -(-hd // BLOCK) * BLOCK
+
+
+def lane_dim(hd: int) -> int:
+    """Minor dim of the raw fused pages: head_dim padded to whole 128-lane
+    rows. The paged kernel's HBM->VMEM copies need a lane-aligned minor
+    dim (XLA's TPU layouts tile it in 128 lanes anyway)."""
+    return -(-hd // LANES) * LANES
 
 
 def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
@@ -50,9 +60,21 @@ def split_kv(kv: jax.Array) -> tuple[jax.Array, jax.Array]:
     return kv[..., 0::2, :], kv[..., 1::2, :]
 
 
+def fuse_pages(k: jax.Array, v: jax.Array) -> jax.Array:
+    """Raw k, v [..., H, Dh] -> fused pages [..., 2H, lane_dim(Dh)]."""
+    d = lane_dim(k.shape[-1])
+    return fuse_kv(_pad_d(k, d), _pad_d(v, d))
+
+
+def split_pages(kv: jax.Array, hd: int) -> tuple[jax.Array, jax.Array]:
+    """Fused pages [..., 2H, lane_dim(hd)] -> (k, v) each [..., H, hd]."""
+    k, v = split_kv(kv)
+    return k[..., :hd], v[..., :hd]
+
+
 def fused_cache_init(batch: int, w: int, n_kv: int, hd: int,
                      dtype=jnp.bfloat16) -> dict:
-    return {"kv": jnp.zeros((batch, w, 2 * n_kv, hd), dtype)}
+    return {"kv": jnp.zeros((batch, w, 2 * n_kv, lane_dim(hd)), dtype)}
 
 
 def fused_quant_init(batch: int, w: int, n_kv: int, hd: int) -> dict:
@@ -98,13 +120,13 @@ def quant_page_step(quant: dict, kv: jax.Array, rows: jax.Array,
     """Per-step resident update of the fused mirrors — the fused port of
     ``layers.attention._quant_cache_step``: re-quantize only the written
     K row and the active 32-slot V block, reading raw values back from
-    the just-updated fused pool ``kv`` [P, W, 2Hkv, Dh] at pool rows
-    ``rows`` (int32 [L], one per decode lane; ``slot`` int32 [L])."""
-    w, hd = kv.shape[1], kv.shape[3]
+    the just-updated fused pool ``kv`` [P, W, 2Hkv, lane_dim(Dh)] at pool
+    rows ``rows`` (int32 [L], one per decode lane; ``slot`` int32 [L])."""
+    w, hd = kv.shape[1], quant["v_exps"].shape[-1]
     hkv = kv.shape[2] // 2
     dpad = padded_head_dim(hd)
     even = 2 * jnp.arange(hkv)
-    kq = mxlib.quantize(kv[rows, slot][:, 0::2].astype(jnp.float32))
+    kq = mxlib.quantize(kv[rows, slot][:, 0::2, :hd].astype(jnp.float32))
     out = {
         "kv_codes": quant["kv_codes"].at[
             rows[:, None], slot[:, None], even[None, :]
@@ -113,7 +135,7 @@ def quant_page_step(quant: dict, kv: jax.Array, rows: jax.Array,
     }
     start = (slot // BLOCK) * BLOCK  # [L]
     idx = start[:, None] + jnp.arange(BLOCK)  # [L, 32]
-    blk = kv[rows[:, None], jnp.minimum(idx, w - 1)][..., 1::2, :]
+    blk = kv[rows[:, None], jnp.minimum(idx, w - 1)][..., 1::2, :hd]
     blk = jnp.where((idx < w)[:, :, None, None], blk, 0)  # partial end block
     vq = mxlib.quantize_axis(blk.astype(jnp.float32), 1)  # [L, Hkv, Dh, 32]
     v_codes = _pad_d(jnp.moveaxis(vq.codes, -1, 1), dpad)  # [L, 32, Hkv, Dpad]

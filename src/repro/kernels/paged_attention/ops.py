@@ -42,13 +42,13 @@ def ragged_paged_decode(
     rows: jax.Array,  # int32 [L] pool row per lane
     lengths: jax.Array,  # int32 [L] valid slots per lane
     *,
-    kv: jax.Array | None = None,  # fused raw pages [P, W, 2Hkv, Dh]
+    kv: jax.Array | None = None,  # fused raw pages [P, W, 2Hkv, >= Dh]
     quant: dict | None = None,  # fused code mirrors (quantized-resident)
     scale: float,
     use_pallas: bool = False,
     interpret: bool | None = None,
     bk: int | None = None,
-    buffers: int | None = None,
+    buffers: int | None = None,  # DMA ring depth (float kernel only)
     obs=None,  # repro.obs.Obs: named timing scope + optional wall capture
 ) -> jax.Array:
     """Returns [L, Hkv, G, Dh]. Exactly one of ``kv`` / ``quant``."""
@@ -76,7 +76,6 @@ def ragged_paged_decode(
         "paged_attention.mx", obs,
         lambda: pk.paged_flash_decode_mx(
             q, quant["kv_codes"], quant["k_exps"], quant["v_exps"], rows,
-            lengths, scale=scale, bk=bk, buffers=buffers,
-            interpret=interpret,
+            lengths, scale=scale, bk=bk, interpret=interpret,
         ),
     )

@@ -29,7 +29,7 @@ def ragged_paged_decode_ref(
     rows: jax.Array,  # int32 [L] pool row per lane
     lengths: jax.Array,  # int32 [L] valid slots per lane, in [0, W]
     *,
-    kv: jax.Array | None = None,  # [P, W, 2Hkv, Dh] raw pages (float path)
+    kv: jax.Array | None = None,  # [P, W, 2Hkv, >= Dh] raw pages (float path)
     quant: dict | None = None,  # fused code mirrors (quantized-resident)
     scale: float,
 ) -> jax.Array:
@@ -47,8 +47,8 @@ def ragged_paged_decode_ref(
         )
         w = kvc.shape[1]
     else:
-        pages = jnp.take(kv, rows, axis=0)  # [L, W, 2Hkv, Dh]
-        kd, vd = layout.split_kv(pages)
+        pages = jnp.take(kv, rows, axis=0)  # [L, W, 2Hkv, >= Dh]
+        kd, vd = layout.split_pages(pages, hd)
         w = kv.shape[1]
     valid = jnp.arange(w)[None, :] < lengths[:, None]  # [L, W]
     sc = jnp.einsum(

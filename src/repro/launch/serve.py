@@ -182,30 +182,36 @@ def _finish_metrics(args, obs: obs_lib.Obs, log) -> None:
         log.info("metrics written: %s", obs_lib.kv(json=jp, prom=pp))
 
 
+def engine_config(args, cfg):
+    """The ``EngineConfig`` the CLI's engine paths serve with. Page budget:
+    full-attention archs take prompt+tokens; sliding-window archs keep the
+    page inside the narrowest window (no ring wrap)."""
+    from repro.serving import EngineConfig
+
+    windows = [s.attn.window for s in build_segments(cfg)
+               if s.attn is not None and s.attn.window > 0]
+    page_len = args.prompt_len + args.tokens
+    if windows:
+        page_len = min(page_len, min(windows))
+    return EngineConfig(
+        lanes=args.lanes, num_slots=args.slots, page_len=page_len,
+        prefill_len=max(2, page_len - args.tokens), policy=args.policy,
+        kv_layout=args.kv_layout, chunk_len=args.chunk_len or None,
+        prefix_cache=args.prefix_cache,
+    )
+
+
 def serve_trace(args, cfg, params, ctx, obs: obs_lib.Obs):
     """Continuous-batching serving demo: a burst of staggered synthetic
     requests through ``serving.Engine``, then the schedule mapped onto the
     twelve-stage FWS pipeline model (simulated latency / throughput)."""
     import numpy as np
 
-    from repro.serving import Engine, EngineConfig
+    from repro.serving import Engine
 
     log = obs_lib.get_logger("repro.serve", args.log_level)
-
-    # page budget: full-attention archs take prompt+tokens; sliding-window
-    # archs must keep the page inside the narrowest window (no ring wrap)
-    windows = [s.attn.window for s in build_segments(cfg)
-               if s.attn is not None and s.attn.window > 0]
-    page_len = args.prompt_len + args.tokens
-    if windows:
-        page_len = min(page_len, min(windows))
-    prefill_len = max(2, page_len - args.tokens)
-    ecfg = EngineConfig(
-        lanes=args.lanes, num_slots=args.slots, page_len=page_len,
-        prefill_len=prefill_len, policy=args.policy,
-        kv_layout=args.kv_layout, chunk_len=args.chunk_len or None,
-        prefix_cache=args.prefix_cache,
-    )
+    ecfg = engine_config(args, cfg)
+    page_len, prefill_len = ecfg.page_len, ecfg.prefill_len
     eng = Engine(params, cfg, ctx, ecfg, obs=obs)
     t0 = time.time()
     tokens_done = 0
@@ -293,23 +299,13 @@ def serve_load(args, cfg, params, ctx, obs: obs_lib.Obs):
     scores SLOs and publishes the load report."""
     import numpy as np
 
-    from repro.serving import Engine, EngineConfig
+    from repro.serving import Engine
     from repro.serving import load as load_mod
 
     log = obs_lib.get_logger("repro.serve", args.log_level)
-    windows = [s.attn.window for s in build_segments(cfg)
-               if s.attn is not None and s.attn.window > 0]
-    page_len = args.prompt_len + args.tokens
-    if windows:
-        page_len = min(page_len, min(windows))
-    prefill_len = max(2, page_len - args.tokens)
-    chunk = args.chunk_len or None
-    ecfg = EngineConfig(
-        lanes=args.lanes, num_slots=args.slots, page_len=page_len,
-        prefill_len=prefill_len, policy=args.policy,
-        kv_layout=args.kv_layout, chunk_len=chunk,
-        prefix_cache=args.prefix_cache,
-    )
+    ecfg = engine_config(args, cfg)
+    page_len, prefill_len, chunk = (ecfg.page_len, ecfg.prefill_len,
+                                    ecfg.chunk_len)
     eng = Engine(params, cfg, ctx, ecfg, obs=obs)
 
     kind, val = load_mod.parse_arrivals(args.arrivals)
@@ -492,7 +488,7 @@ def serve_pipelined_lm(args, cfg, params, ctx, obs: obs_lib.Obs,
     _finish_metrics(args, obs, log)
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", "--model", dest="arch", default="gemma3-1b")
     ap.add_argument("--tiny", action="store_true", default=True,
@@ -590,7 +586,14 @@ def main():
                     help="TTFT p99 SLO target in ms (host wall)")
     ap.add_argument("--slo-token-ms", type=float, default=None,
                     help="per-token latency p99 SLO target in ms")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+    args = parser().parse_args()
 
     log = obs_lib.get_logger("repro.serve", args.log_level)
 

@@ -421,10 +421,10 @@ def attn_apply(
         slot = jnp.where(positions > _KV_PAD_MIN, positions, w)
         if fused:
             dt = cache["kv"].dtype
-            kvnew = paged_layout.fuse_kv(k.astype(dt), v.astype(dt))
+            kvnew = paged_layout.fuse_pages(k.astype(dt), v.astype(dt))
             ckv = cache["kv"].at[rows, slot].set(kvnew, mode="drop")
             new_cache = {"kv": ckv}
-            kpage, vpage = paged_layout.split_kv(ckv)
+            kpage, vpage = paged_layout.split_pages(ckv, hd)
             if "kv_codes" in cache:
                 new_cache.update(paged_layout.quant_page_full(kpage, vpage))
         else:
@@ -463,7 +463,7 @@ def attn_apply(
         if fused:
             dt = cache["kv"].dtype
             kcast, vcast = kw.astype(dt), vw.astype(dt)
-            new_cache = {"kv": paged_layout.fuse_kv(kcast, vcast)}
+            new_cache = {"kv": paged_layout.fuse_pages(kcast, vcast)}
             if "kv_codes" in cache:
                 # same quantize calls as the legacy mirror fill, repacked
                 new_cache.update(paged_layout.quant_page_full(kcast, vcast))
@@ -495,7 +495,7 @@ def attn_apply(
         rows = (jnp.arange(b) if ctx.paged_rows is None
                 else ctx.paged_rows)
         slot = pos_b % w
-        kvnew = paged_layout.fuse_kv(k[:, 0], v[:, 0])
+        kvnew = paged_layout.fuse_pages(k[:, 0], v[:, 0])
         ckv = cache["kv"].at[rows, slot].set(
             kvnew.astype(cache["kv"].dtype)
         )

@@ -146,18 +146,25 @@ class Engine:
             pool = scatter_rows(pool, specs, row, caches)
             return jnp.argmax(logits[0, 0].astype(jnp.float32)), pool
 
-        def decode(params, pool, rows, ids, pos):
+        def decode_legacy(params, pool, rows, ids, pos):
             caches = gather_rows(pool, specs, rows)
             logits, caches = lm.decode_step(params, cfg, ctx, ids, pos, caches)
-            pool = scatter_rows(pool, specs, rows, caches)
-            return jnp.argmax(logits.astype(jnp.float32), -1), pool
+            return logits, scatter_rows(pool, specs, rows, caches)
 
         def decode_fused(params, pool, rows, ids, pos):
             # in-place paged decode: lanes address their pool rows through
             # RunCtx.paged_rows (threaded inside the trace — never closed
             # over), so no page gather/scatter brackets the step
             dctx = dataclasses.replace(ctx, paged_rows=rows)
-            logits, pool = lm.decode_step(params, cfg, dctx, ids, pos, pool)
+            return lm.decode_step(params, cfg, dctx, ids, pos, pool)
+
+        # the un-jitted decode body: (params, pool, rows, ids, pos) ->
+        # ([lanes, V] logits, pool); the jitted step keeps only the argmax
+        decode_logits = decode_fused if self.kv.fused else decode_legacy
+        self.decode_logits = decode_logits
+
+        def decode(params, pool, rows, ids, pos):
+            logits, pool = decode_logits(params, pool, rows, ids, pos)
             return jnp.argmax(logits.astype(jnp.float32), -1), pool
 
         def chunk(params, pool, row, ids, positions, offset, last):
@@ -181,8 +188,7 @@ class Engine:
         chunked = ecfg.chunk_len is not None
         return (
             jax.jit(prefill, donate_argnums=(1,)),
-            jax.jit(decode_fused if self.kv.fused else decode,
-                    donate_argnums=(1,)),
+            jax.jit(decode, donate_argnums=(1,)),
             jax.jit(chunk, donate_argnums=(1,)) if chunked else None,
             jax.jit(clone, donate_argnums=(0,)) if chunked else None,
         )

@@ -23,8 +23,15 @@ def _packed_weight(key, k, n):
     return w, wq, codes, exps
 
 
+# The last three are ViT-B/16's tiles: several K tiles accumulating at
+# bk=256 and bk=512 (768, 3072), and the 1000-class head as one N tile.
+WIDE_TILES = [(16, 768, 256), (8, 3072, 128), (4, 768, 1000)]
+
+
 @pytest.mark.parametrize(
-    "m,k,n", [(8, 64, 16), (128, 128, 128), (33, 96, 48), (256, 512, 64)]
+    "m,k,n",
+    [(8, 64, 16), (128, 128, 128), (33, 96, 48), (256, 512, 64)]
+    + WIDE_TILES,
 )
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_mxfp4_matmul_sweep(m, k, n, dtype):
@@ -51,7 +58,7 @@ def test_mxfp4_matmul_batched_and_bitexact_dequant():
     np.testing.assert_array_equal(d1, d2)
 
 
-@pytest.mark.parametrize("m,k,n", [(16, 64, 16), (64, 128, 32)])
+@pytest.mark.parametrize("m,k,n", [(16, 64, 16), (64, 128, 32)] + WIDE_TILES)
 @pytest.mark.parametrize("adc,cm,two", [(10, 3, True), (None, 2, False), (8, 4, True)])
 def test_cim_linear_kernel_matches_sim(m, k, n, adc, cm, two):
     kx, kw = jax.random.split(jax.random.PRNGKey(m + k + n + cm))

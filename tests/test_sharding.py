@@ -7,12 +7,13 @@ import pytest
 
 from repro import configs as C
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_local_mesh
 from repro.layers.common import ShardingCtx
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh()
 
 
 def test_rules_head_shardability(mesh):
@@ -45,7 +46,7 @@ def test_resolve_no_axis_reuse(mesh):
 
 
 def test_divisibility_drops_axis(mesh):
-    big = jax.make_mesh((1, 1), ("data", "model"))
+    big = make_local_mesh()
     ctx = ShardingCtx(mesh=big, rules={"batch": ("data",), "x": "model"})
     out = shd.resolve_with_divisibility(
         ("batch", "x"), jax.ShapeDtypeStruct((1, 7), jnp.float32), ctx, big
@@ -65,7 +66,7 @@ def test_fsdp_param_rules(mesh):
 
 def test_opt_state_zero_specs():
     specs = {"w": ("embed", "mlp"), "g": ("embed",)}
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_local_mesh()
     z = shd.opt_state_specs(specs, None, m, zero1=True)
     assert z["w"] == ("zero", "mlp")
 
