@@ -16,7 +16,6 @@ from repro.kernels.mxfp4_matmul.ops import (
     pick_bm,
     pick_bn,
 )
-from repro.obs.profile import profiled_call
 
 
 def cim_linear(
@@ -26,7 +25,6 @@ def cim_linear(
     *,
     cfg: cimlib.CIMConfig | None = None,
     interpret: bool | None = None,  # None -> platform default
-    obs=None,  # repro.obs.Obs: named timing scope + optional wall capture
 ) -> jax.Array:
     """x [..., K] float -> [..., N] f32 through the analog CIM kernel."""
     cfg = cfg or cimlib.CIMConfig()
@@ -42,15 +40,13 @@ def cim_linear(
     cal = jnp.array(
         [[jnp.asarray(calib.e_n, jnp.float32), calib.adc_fs]], jnp.float32
     )
-    out = profiled_call(
-        "cim_linear", obs,
-        lambda: cim_linear_kernel(
+    with jax.named_scope("repro/cim_linear"):
+        out = cim_linear_kernel(
             xm, w.codes, w.exps, cal,
             bm=bm, bn=pick_bn(n), bk=pick_bk(k), cm=cfg.cm_bits,
             adc_bits=cfg.adc_bits, two_pass=cfg.two_pass,
             interpret=interpret,
-        ),
-    )
+        )
     if pm:
         out = out[:m]
     return out.reshape(lead + (n,))

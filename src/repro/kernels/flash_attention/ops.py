@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
-from repro.obs.profile import profiled_call
 
 
 def flash_attention(
@@ -18,7 +17,6 @@ def flash_attention(
     window: int = 0,
     q_offset: int = 0,
     interpret: bool | None = None,  # None -> platform default
-    obs=None,  # repro.obs.Obs: named timing scope + optional wall capture
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -26,11 +24,9 @@ def flash_attention(
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    o = profiled_call(
-        "flash_attention", obs,
-        lambda: flash_attention_kernel(
+    with jax.named_scope("repro/flash_attention"):
+        o = flash_attention_kernel(
             qf, kf, vf, groups=g, causal=causal, window=window,
             q_offset=q_offset, interpret=interpret,
-        ),
-    )
+        )
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

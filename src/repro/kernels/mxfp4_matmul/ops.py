@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.mxfp4_matmul.kernel import mxfp4_matmul_kernel
-from repro.obs.profile import profiled_call
 
 
 def _round_up(x: int, m: int) -> int:
@@ -54,7 +53,6 @@ def mxfp4_matmul(
     exps: jax.Array,
     *,
     interpret: bool | None = None,  # None -> platform default
-    obs=None,  # repro.obs.Obs: named timing scope + optional wall capture
 ) -> jax.Array:
     """x [..., K] @ dequant(codes [K//2, N], exps [K//32, N]) -> [..., N]."""
     lead = x.shape[:-1]
@@ -66,13 +64,11 @@ def mxfp4_matmul(
     pm = _round_up(m, bm) - m
     if pm:
         xm = jnp.pad(xm, ((0, pm), (0, 0)))
-    out = profiled_call(
-        "mxfp4_matmul", obs,
-        lambda: mxfp4_matmul_kernel(
+    with jax.named_scope("repro/mxfp4_matmul"):
+        out = mxfp4_matmul_kernel(
             xm, codes, exps, bm=bm, bn=pick_bn(n), bk=pick_bk(k),
             out_dtype=jnp.bfloat16, interpret=interpret,
-        ),
-    )
+        )
     if pm:
         out = out[:m]
     return out.reshape(lead + (n,))

@@ -15,7 +15,6 @@ import jax
 from repro.core import mx as mxlib
 from repro.kernels.paged_attention import kernel as pk
 from repro.kernels.paged_attention import ref as pref
-from repro.obs.profile import profiled_call
 
 BLOCK = mxlib.BLOCK
 MAX_BK = 128
@@ -49,33 +48,26 @@ def ragged_paged_decode(
     interpret: bool | None = None,
     bk: int | None = None,
     buffers: int | None = None,  # DMA ring depth (float kernel only)
-    obs=None,  # repro.obs.Obs: named timing scope + optional wall capture
 ) -> jax.Array:
     """Returns [L, Hkv, G, Dh]. Exactly one of ``kv`` / ``quant``."""
     if (kv is None) == (quant is None):
         raise ValueError("pass exactly one of kv= (float) or quant= (mx)")
     if not use_pallas:
-        return profiled_call(
-            "paged_attention.ref", obs,
-            lambda: pref.ragged_paged_decode_ref(
+        with jax.named_scope("repro/paged_attention.ref"):
+            return pref.ragged_paged_decode_ref(
                 q, rows, lengths, kv=kv, quant=quant, scale=scale
-            ),
-        )
+            )
     w = (kv if quant is None else quant["kv_codes"]).shape[1]
     bk = bk or pick_bk(w)
     buffers = buffers or pick_buffers(w, bk)
     if quant is None:
-        return profiled_call(
-            "paged_attention", obs,
-            lambda: pk.paged_flash_decode(
+        with jax.named_scope("repro/paged_attention"):
+            return pk.paged_flash_decode(
                 q, kv, rows, lengths, scale=scale, bk=bk, buffers=buffers,
                 interpret=interpret,
-            ),
-        )
-    return profiled_call(
-        "paged_attention.mx", obs,
-        lambda: pk.paged_flash_decode_mx(
+            )
+    with jax.named_scope("repro/paged_attention.mx"):
+        return pk.paged_flash_decode_mx(
             q, quant["kv_codes"], quant["k_exps"], quant["v_exps"], rows,
             lengths, scale=scale, bk=bk, interpret=interpret,
-        ),
-    )
+        )

@@ -14,8 +14,8 @@ against the paper's Table 7 FPS row (dual-chip 12+12 for vit-l32).
 Telemetry: every run carries a ``repro.obs`` handle — request-trace
 spans + pipeline occupancy metrics land in a metrics registry that
 ``--metrics-out PATH`` dumps as a JSON snapshot plus a Prometheus text
-exposition (``PATH`` with a ``.prom`` suffix). ``--profile`` turns on
-eager kernel wall-clock capture (named scopes are always on);
+exposition (``PATH`` with a ``.prom`` suffix); the engines' step phases
+land there as ``serve_step_phase_seconds{kind,phase}``.
 ``--slo-ttft-ms`` / ``--slo-token-ms`` score the run against latency
 targets. ``--log-level`` controls the structured per-step log lines.
 ``--fidelity`` adds a numerical-fidelity pass over the freshly built
@@ -65,20 +65,18 @@ def conversion_args(args) -> dict:
     )
 
 
-def build_backend(args, cfg, params, batches=None, forward_fn=None,
-                  obs=None):
+def build_backend(args, cfg, params, batches=None, forward_fn=None):
     """Returns (converted_params, RunCtx) for the requested backend.
 
     ``batches``/``forward_fn`` select the calibration capture for the cim
     backend (default: LM token batches through ``lm.forward``; the vision
-    path passes synthetic images through ``vit.forward``). ``obs`` is the
-    telemetry handle threaded into the RunCtx (kernel profiling scopes).
-    All conversion knobs come from :func:`conversion_args` — callers no
+    path passes synthetic images through ``vit.forward``). All
+    conversion knobs come from :func:`conversion_args` — callers no
     longer plumb them per path.
     """
     conv_kw = conversion_args(args)
     shd = ShardingCtx()
-    kw = dict(shd=shd, dense_attn_max=256, impl=args.impl, obs=obs)
+    kw = dict(shd=shd, dense_attn_max=256, impl=args.impl)
     if getattr(args, "interpret", None) is not None:
         kw["interpret"] = args.interpret  # else: platform default
     log = obs_lib.get_logger("repro.serve", getattr(args, "log_level", "info"))
@@ -129,10 +127,6 @@ def pipeline_shape(args) -> tuple[int, int] | None:
     if args.stages:
         return (1, args.stages)
     return None
-
-
-def _mk_obs(args) -> obs_lib.Obs:
-    return obs_lib.Obs(profile=args.profile)
 
 
 def _run_fidelity(args, cfg, fparams, params, ctx, obs, batch,
@@ -366,7 +360,7 @@ def serve_vision(args, cfg_full):
     from repro.serving.vision import VisionEngine
 
     log = obs_lib.get_logger("repro.serve", args.log_level)
-    obs = _mk_obs(args)
+    obs = obs_lib.Obs()
 
     # --tiny keeps the paper's token geometry (patch grid, layers, chips)
     # and shrinks only the width, so the measured traffic still reproduces
@@ -378,7 +372,6 @@ def serve_vision(args, cfg_full):
     )
     params, ctx = build_backend(
         args, cfg, fparams, batches=batches, forward_fn=vit.forward,
-        obs=obs,
     )
     if args.fidelity:
         _run_fidelity(args, cfg, fparams, params, ctx, obs, batches[0],
@@ -571,10 +564,6 @@ def parser() -> argparse.ArgumentParser:
                          "underflow + ADC saturation/code-utilization "
                          "counters, calibration-drift check (eager; "
                          "metrics land in --metrics-out)")
-    ap.add_argument("--profile", action="store_true",
-                    help="capture eager kernel wall clock (named scopes "
-                         "are always on; this adds block_until_ready "
-                         "serialization, so it is off by default)")
     ap.add_argument("--log-level", default="info", choices=obs_lib.log.LEVELS
                     if hasattr(obs_lib, "log") else
                     ("debug", "info", "warning", "error"),
@@ -604,9 +593,9 @@ def main():
     cfg = C.tiny(C.ARCHS[args.arch]) if args.tiny else C.ARCHS[args.arch]
     if not cfg.supports_decode:
         raise SystemExit(f"{cfg.name} is encoder-only; no decode")
-    obs = _mk_obs(args)
+    obs = obs_lib.Obs()
     fparams, _ = lm.init_model(jax.random.PRNGKey(0), cfg)
-    params, ctx = build_backend(args, cfg, fparams, obs=obs)
+    params, ctx = build_backend(args, cfg, fparams)
     if args.fidelity:
         fb = calibrate.calibration_batches(
             cfg, n_batches=1, batch=args.batch, seq=args.prompt_len

@@ -526,7 +526,6 @@ def attn_apply(
             scale=cfg.scale, use_pallas=ctx.use_pallas,
             interpret=ctx.interpret,
             buffers=ctx.paged_buffers or None,
-            obs=ctx.obs,
         )[:, None]
     elif cache is not None:
         # pos may be a scalar (all lanes at the same position) or a [B]

@@ -220,7 +220,6 @@ class _MXFP4WeightOnly(LinearBackend):
 
             return mmops.mxfp4_matmul(
                 x, params["codes"], params["exps"], interpret=ctx.interpret,
-                obs=ctx.obs,
             )
         w = _dequant_packed(params["codes"], params["exps"])
         return jnp.matmul(x.astype(jnp.bfloat16), w)
@@ -273,7 +272,7 @@ class _CIMAnalog(LinearBackend):
             from repro.kernels.cim_linear import ops as cim_ops
 
             y = cim_ops.cim_linear(
-                x, w, calib, cfg=cfg, interpret=ctx.interpret, obs=ctx.obs
+                x, w, calib, cfg=cfg, interpret=ctx.interpret
             )
         else:
             y, _ = cimlib.cim_linear(x, w, cfg, calib)

@@ -142,12 +142,6 @@ class RunCtx:
     # must never be closed over as a static value.
     paged_rows: Any = None
     paged_buffers: int = 0  # DMA ring depth override for the paged kernel (0: auto)
-    # Telemetry handle (repro.obs.Obs), threaded into the kernel ops
-    # wrappers: named profiling scopes + dispatch counters, and — only
-    # with obs.profile=True — eager wall-clock capture. None keeps the
-    # bare named scopes (zero runtime cost). Host-side Python object:
-    # only ever closed over, never traced.
-    obs: Any = None
     # Numerical-fidelity probe (repro.obs.FidelityProbe) during an eager
     # instrumented run: per-layer MXFP4/ADC health keyed by the same
     # scoped paths as calibration. Host-side Python object — only ever

@@ -6,11 +6,10 @@
 - :mod:`repro.obs.tracing` — the :class:`Obs` handle the serving
   engines emit typed lifecycle events through (enqueue -> admitted ->
   prefill/first-token -> decode steps -> finish/evict), yielding TTFT,
-  queue-wait, per-token latency, occupancy and eviction metrics; the
-  old ``(kind, rids, n_tokens)`` tuple trace is a derived view.
-- :mod:`repro.obs.profile` — named kernel timing scopes
-  (``jax.named_scope`` + ``jax.profiler.TraceAnnotation``) with
-  optional eager wall-clock capture behind ``Obs.profile``.
+  queue-wait, per-token latency, occupancy and eviction metrics, and
+  ``Obs.span``, the engines' per-step host phases on both the host
+  clock and the profiler's; the old ``(kind, rids, n_tokens)`` tuple
+  trace is a derived view.
 - :mod:`repro.obs.export` — Prometheus text exposition + JSON snapshot
   writers (and the parser the round-trip test uses).
 - :mod:`repro.obs.slo` — configurable TTFT / per-token latency targets
@@ -37,7 +36,6 @@ from repro.obs.fidelity import (  # noqa: F401
     sqnr_trace,
 )
 from repro.obs.log import get_logger, kv  # noqa: F401
-from repro.obs.profile import profiled_call  # noqa: F401
 from repro.obs.registry import (  # noqa: F401
     EXP_BUCKETS,
     LATENCY_BUCKETS_S,

@@ -7,13 +7,23 @@ for the vision engine — and the tracer turns them into per-request
 metrics (TTFT, queue wait, per-token latency, end-to-end latency) plus
 registry counters/gauges/histograms.
 
+Each step runs under :meth:`Obs.step_span`, which owns the step's record
+(its bounds, and the event kept when it closes), and the engines mark its
+host phases with :meth:`Obs.span`. Both are ``jax.profiler.TraceAnnotation``
+s (so a profiled run shows them on the device ops' clock); when enabled,
+each also leaves a ``(name, t0, t1)`` record on ``Obs.clock`` in the
+step's ``StepEvent.phases`` and the ``serve_step_phase_seconds{kind,phase}``
+histogram, and a step far slower than the median of its kind is logged
+with its phases.
+
 The old ad-hoc ``(kind, rids, n_tokens)`` tuple list survives as a
 *derived view* (:meth:`Obs.legacy_trace`) so ``pipeline.simulate_trace``
 and every existing consumer keep working unchanged.
 
 ``Obs(enabled=False)`` keeps the step-event record (the pre-PR trace
 equivalent, needed by the pipeline model) but skips all per-request
-span tracking and registry updates — the measured-overhead baseline.
+span tracking, phase records and registry updates; its spans are bare
+profiler annotations — the measured-overhead baseline.
 """
 
 from __future__ import annotations
@@ -21,7 +31,15 @@ from __future__ import annotations
 import dataclasses
 import time
 
+import jax
+
+from repro.obs import log as log_mod
 from repro.obs import registry as reg_mod
+
+# a step whose wall exceeds this many times the median of its kind (once
+# 16 of them were seen), and SLOW_STEP_MIN_S, is logged with its phases
+SLOW_STEP = 5.0
+SLOW_STEP_MIN_S = 0.01
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,7 +51,11 @@ class StepEvent:
     rids: tuple
     n_tokens: int
     t_start: float
-    t_end: float
+    t_end: float  # the step's result was on the host (after its sync)
+    # (name, t0, t1) of the step's span and of each phase inside it, by
+    # start; the span ends after the sync, once the tokens are emitted.
+    # Empty when the Obs is disabled.
+    phases: tuple = ()
 
     @property
     def legacy(self) -> tuple:
@@ -86,28 +108,143 @@ class RequestMetrics:
         return [b - a for a, b in zip(t, t[1:])]
 
 
+class _Span:
+    """:meth:`Obs.span`'s context manager (a class: a generator-based one
+    costs a few microseconds more on every step)."""
+
+    __slots__ = ("obs", "name", "ann", "t0")
+
+    def __init__(self, obs: "Obs", name: str, meta: dict):
+        self.obs = obs
+        self.name = name
+        self.ann = jax.profiler.TraceAnnotation(name, **meta)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        if self.obs._phases is not None:
+            self.t0 = self.obs.clock()
+        return self
+
+    def __exit__(self, *exc):
+        phases = self.obs._phases
+        if phases is not None:
+            phases.append((self.name, self.t0, self.obs.clock()))
+        self.ann.__exit__(*exc)
+        return False
+
+
+class _StepSpan:
+    """:meth:`Obs.step_span`'s context manager: the step's own span, which
+    owns its record."""
+
+    __slots__ = ("obs", "name", "ann", "t0", "t_end", "events")
+
+    def __init__(self, obs: "Obs", name: str, meta: dict):
+        self.obs = obs
+        self.name = name
+        self.ann = jax.profiler.TraceAnnotation(name, **meta)
+        self.t_end = None
+        self.events: list = []
+
+    def __enter__(self):
+        self.ann.__enter__()
+        obs = self.obs
+        if obs.enabled:
+            obs._phases = []
+        self.t0 = obs.clock()
+        return self
+
+    def record(self, kind: str, rids: tuple, n_tokens: int,
+               lanes: int | None = None) -> float:
+        """The step's result is on the host: keep a step event for when
+        the span closes. The first call stamps the step's end, which it
+        returns; a step that records several events (a slab of frames)
+        bills each an equal slice of its wall."""
+        if self.t_end is None:
+            self.t_end = self.obs.clock()
+        self.events.append((kind, tuple(rids), n_tokens, lanes))
+        return self.t_end
+
+    def __exit__(self, *exc):
+        obs = self.obs
+        phases, obs._phases = obs._phases, None
+        try:
+            if phases is not None:
+                phases.sort(key=lambda p: (p[1], -p[2]))
+                phases.insert(0, (self.name, self.t0, obs.clock()))
+            t0, t1, k = self.t0, self.t_end, len(self.events)
+            for j, (kind, rids, n, lanes) in enumerate(self.events):
+                last = j == k - 1
+                obs.step_recorded(
+                    kind, rids, n, t0 + (t1 - t0) * j / k,
+                    t1 if k == 1 else t0 + (t1 - t0) * (j + 1) / k,
+                    lanes=lanes,
+                    phases=tuple(phases) if last and phases else ())
+        finally:
+            self.ann.__exit__(*exc)
+        return False
+
+
 class Obs:
     """Telemetry handle threaded through the serving stack.
 
-    Carries the metrics registry, the step-event record, per-request
-    spans, and the kernel-profiling switches. Engines accept one at
+    Carries the metrics registry, the step-event record with each
+    step's phases, and per-request spans. Engines accept one at
     construction; ``None`` means "create a private enabled one", so
     telemetry is on by default without any caller changes.
     """
 
     def __init__(self, registry: reg_mod.MetricsRegistry | None = None,
-                 enabled: bool = True, profile: bool = False,
-                 clock=time.perf_counter):
+                 enabled: bool = True, clock=time.perf_counter):
         self.registry = registry or reg_mod.MetricsRegistry()
         self.enabled = enabled
-        # profile=True additionally captures kernel wall clock via
-        # block_until_ready in eager paths (see repro.obs.profile) —
-        # off by default, it serializes dispatch
-        self.profile = profile
         self.clock = clock
         self.steps: list[StepEvent] = []
         self.live: dict[int, RequestMetrics] = {}
         self.finished: list[RequestMetrics] = []
+        # the closed phases of the open step; None outside an enabled one
+        self._phases: list | None = None
+        self._hists: dict = {}  # (kind, phase) -> its histogram
+
+    # ------------------------------------------------------------ spans
+
+    def step_span(self, name: str, **meta) -> "_StepSpan":
+        """One scheduled step: a profiler annotation carrying ``meta``
+        that owns the step's record, which runs from the span's start to
+        its first :meth:`_StepSpan.record` call (the result is on the
+        host). The event(s) recorded are kept when the span closes, even
+        when disabled; when enabled, with the step's own ``(name, t0,
+        t1)`` and those of the :meth:`span` phases inside it as their
+        ``phases``."""
+        return _StepSpan(self, name, meta)
+
+    def span(self, name: str, **meta) -> "_Span":
+        """A named host phase of the open step: a profiler annotation
+        carrying ``meta`` and, when enabled, a ``(name, t0, t1)`` record
+        in the step's phases. Outside a step it is only the annotation."""
+        return _Span(self, name, meta)
+
+    def _phase_hist(self, kind: str, phase: str) -> reg_mod.Histogram:
+        h = self._hists.get((kind, phase))
+        if h is None:
+            h = self._hists[kind, phase] = self.registry.histogram(
+                "serve_step_phase_seconds",
+                "host wall per step phase (the step's own span included)",
+                labels={"kind": kind, "phase": phase})
+        return h
+
+    def _observe_phases(self, kind: str, phases: tuple) -> None:
+        name, t0, t1 = phases[0]
+        h = self._phase_hist(kind, name)
+        if t1 - t0 > SLOW_STEP_MIN_S and h.count >= 16:
+            med = h.quantile(0.5)
+            if t1 - t0 > SLOW_STEP * med:
+                log_mod.get_logger("repro.obs").warning(
+                    "slow step %s: %.4f s, median %.4f s: %s", name,
+                    t1 - t0, med,
+                    log_mod.kv(**{n: b - a for n, a, b in phases[1:]}))
+        for name, t0, t1 in phases:
+            self._phase_hist(kind, name).observe(t1 - t0)
 
     # -------------------------------------------------- request lifecycle
 
@@ -183,13 +320,15 @@ class Obs:
 
     def step_recorded(self, kind: str, rids: tuple, n_tokens: int,
                       t_start: float, t_end: float,
-                      lanes: int | None = None) -> None:
+                      lanes: int | None = None, phases: tuple = ()) -> None:
         """Record one scheduled step. Always kept (it is the pipeline
         model's input); registry updates only when enabled."""
         self.steps.append(StepEvent(kind, tuple(rids), n_tokens,
-                                    t_start, t_end))
+                                    t_start, t_end, phases))
         if not self.enabled:
             return
+        if phases:
+            self._observe_phases(kind, phases)
         self.registry.counter(
             "serve_steps_total", "scheduled engine steps by kind",
             labels={"kind": kind},

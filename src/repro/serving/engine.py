@@ -33,7 +33,9 @@ shared-exponent blocks of the quantized-resident mirrors).
 Telemetry: the engine emits typed lifecycle events through a
 ``repro.obs.Obs`` handle — enqueue -> admitted -> prefill/first-token ->
 per-decode-step -> finish/evict — yielding per-request TTFT, queue-wait,
-per-token latency, occupancy and eviction metrics. Prefill step events
+per-token latency, occupancy and eviction metrics — and marks each
+step's host phases with ``Obs.span`` (see :meth:`Engine.step`), so a
+profiled run shows them beside the device ops. Prefill step events
 bill the *executed* width (``prefill_len`` or ``chunk_len``), not the
 prompt length: the jitted step pushes the full padded window through the
 FWS pipeline whether or not the tail is padding, and the pipeline model
@@ -195,8 +197,12 @@ class Engine:
 
     # --------------------------------------------------------- public API
 
-    def add_request(self, prompt, max_new: int, stop_token: int | None = None
-                    ) -> int:
+    def add_request(self, prompt, max_new: int, stop_token: int | None = None,
+                    arrival: float | None = None) -> int:
+        """Queue a request; returns its id. ``arrival`` is when it was due
+        on ``obs.clock`` (default: now): its TTFT and queue wait start
+        there, so a caller that lets requests in between steps does not
+        leave out the wait for the step that was running."""
         prompt = [int(t) for t in prompt]
         limit = (self.ecfg.page_len if self.ecfg.chunk_len is not None
                  else self.ecfg.prefill_len)
@@ -216,12 +222,23 @@ class Engine:
                       stop_token=stop_token, arrival=self._step_idx)
         self.requests[rid] = req
         self.sched.add(req)
-        self.obs.request_enqueued(rid, n_prompt=len(prompt))
+        self.obs.request_enqueued(rid, n_prompt=len(prompt), t=arrival)
         return rid
 
     def step(self) -> list:
         """One scheduled unit of work (a prefill / prefill chunk or a
-        decode step). Returns the requests that finished during it."""
+        decode step). Returns the requests that finished during it.
+
+        The step runs under a step span named for its kind
+        (``engine.prefill``, ``engine.chunk`` or ``engine.decode``,
+        carrying the step index and, for a prefill, the request id) that
+        owns its record; its phases cover it in order:
+        ``engine.schedule`` (admission: slot, prefix match, page clone),
+        ``engine.inputs`` (host arrays and their copies to the device),
+        ``engine.dispatch`` (the jitted call), ``engine.sync`` (waiting
+        for its result) and ``engine.emit`` (tokens, step record,
+        retirement); the lane gauges are set last. ``plan`` runs just
+        before the span, which is named after its outcome."""
         avail = self.kv.num_free + (
             self.prefix.n_evictable if self.prefix is not None else 0
         )
@@ -229,14 +246,18 @@ class Engine:
         if action == "idle":
             return []
         self._step_idx += 1
-        if action == "prefill":
-            done = (self._run_prefill_chunk()
-                    if self.ecfg.chunk_len is not None
-                    else self._run_prefill())
+        meta = {"step": self._step_idx}
+        if action == "decode":
+            name, run = "engine.decode", self._run_decode
         else:
-            done = self._run_decode()
-        self.obs.lanes_state(len(self.sched.waiting), self.sched.num_active,
-                             self.kv.num_free)
+            meta["rid"] = (self.sched.prefilling or self.sched.waiting[0]).rid
+            name, run = (("engine.prefill", self._run_prefill)
+                         if self.ecfg.chunk_len is None
+                         else ("engine.chunk", self._run_prefill_chunk))
+        with self.obs.step_span(name, **meta) as st:
+            done = run(st)
+            self.obs.lanes_state(len(self.sched.waiting),
+                                 self.sched.num_active, self.kv.num_free)
         return done
 
     def run(self, max_steps: int = 100_000) -> dict:
@@ -327,94 +348,102 @@ class Engine:
             self.kv.allocator.release(hit.slot)
         return req
 
-    def _run_prefill(self) -> list:
-        t0 = self.obs.clock()
-        slot = self._alloc_slot()
-        req = self.sched.admit(slot, self._step_idx)
-        self.obs.request_admitted(req.rid)
-        n = len(req.prompt)
-        p = self.ecfg.prefill_len
-        ids = np.zeros((1, p), np.int32)
-        ids[0, :n] = req.prompt
-        positions = np.full((1, p), attn_mod.KV_PAD, np.int32)
-        positions[0, :n] = np.arange(n)
-        tok, self.kv.pool = self._prefill(
-            self.params, self.kv.pool, jnp.asarray(ids),
-            jnp.asarray(positions), jnp.asarray([slot], jnp.int32),
-            jnp.int32(n - 1),
-        )
-        req.out.append(int(tok))  # device sync: the step is complete here
-        t1 = self.obs.clock()
-        # bill the executed width: the fixed-shape step pushes all
-        # prefill_len positions through the pipeline, padding included.
-        # The request span keeps the real prompt length (request_enqueued)
-        # for TTFT/queue accounting.
-        self.obs.step_recorded("prefill", (req.rid,), p, t0, t1)
-        self.obs.token_emitted(req.rid, t1)  # prefill emits the first token
-        return self._retire([req])
+    def _run_prefill(self, st) -> list:
+        obs = self.obs
+        with obs.span("engine.schedule"):
+            slot = self._alloc_slot()
+            req = self.sched.admit(slot, self._step_idx)
+            obs.request_admitted(req.rid)
+        with obs.span("engine.inputs"):
+            n = len(req.prompt)
+            p = self.ecfg.prefill_len
+            ids = np.zeros((1, p), np.int32)
+            ids[0, :n] = req.prompt
+            positions = np.full((1, p), attn_mod.KV_PAD, np.int32)
+            positions[0, :n] = np.arange(n)
+            args = (jnp.asarray(ids), jnp.asarray(positions),
+                    jnp.asarray([slot], jnp.int32), jnp.int32(n - 1))
+        with obs.span("engine.dispatch"):
+            tok, self.kv.pool = self._prefill(self.params, self.kv.pool,
+                                              *args)
+        with obs.span("engine.sync"):
+            tok = int(tok)  # device sync: the step is complete here
+        with obs.span("engine.emit"):
+            # bill the executed width: the fixed-shape step pushes all
+            # prefill_len positions through the pipeline, padding
+            # included. The request span keeps the real prompt length
+            # (request_enqueued) for TTFT/queue accounting.
+            t1 = st.record("prefill", (req.rid,), p)
+            req.out.append(tok)
+            obs.token_emitted(req.rid, t1)  # prefill emits the first token
+            return self._retire([req])
 
-    def _run_prefill_chunk(self) -> list:
-        t0 = self.obs.clock()
+    def _run_prefill_chunk(self, st) -> list:
+        obs = self.obs
         L = self.ecfg.chunk_len
-        req = self.sched.prefilling
-        if req is None:
-            req = self._admit_chunked()
-        offs = req.prefilled
-        take = min(L, len(req.prompt) - offs)
-        ids = np.zeros((1, L), np.int32)
-        ids[0, :take] = req.prompt[offs:offs + take]
-        positions = np.full((1, L), attn_mod.KV_PAD, np.int32)
-        positions[0, :take] = np.arange(offs, offs + take)
-        tok, self.kv.pool = self._chunk(
-            self.params, self.kv.pool, jnp.asarray([req.slot], jnp.int32),
-            jnp.asarray(ids), jnp.asarray(positions), jnp.int32(offs),
-            jnp.int32(take - 1),
-        )
-        tok = int(tok)  # device sync: the step is complete here
-        t1 = self.obs.clock()
-        self.obs.step_recorded("prefill", (req.rid,), L, t0, t1)
-        req.prefilled = offs + take
-        if req.prefilled < len(req.prompt):
-            return []
-        # prompt fully resident: the last chunk's logits are the first
-        # generated token, and the finished prefix becomes donatable
-        self.sched.finish_prefill(req)
-        req.out.append(tok)
-        self.obs.token_emitted(req.rid, t1)
-        if self.prefix is not None:
-            self.prefix.insert(req.prompt, req.slot, self.kv)
-        return self._retire([req])
+        with obs.span("engine.schedule"):
+            req = self.sched.prefilling
+            if req is None:
+                req = self._admit_chunked()
+        with obs.span("engine.inputs"):
+            offs = req.prefilled
+            take = min(L, len(req.prompt) - offs)
+            ids = np.zeros((1, L), np.int32)
+            ids[0, :take] = req.prompt[offs:offs + take]
+            positions = np.full((1, L), attn_mod.KV_PAD, np.int32)
+            positions[0, :take] = np.arange(offs, offs + take)
+            args = (jnp.asarray([req.slot], jnp.int32), jnp.asarray(ids),
+                    jnp.asarray(positions), jnp.int32(offs),
+                    jnp.int32(take - 1))
+        with obs.span("engine.dispatch"):
+            tok, self.kv.pool = self._chunk(self.params, self.kv.pool, *args)
+        with obs.span("engine.sync"):
+            tok = int(tok)  # device sync: the step is complete here
+        with obs.span("engine.emit"):
+            t1 = st.record("prefill", (req.rid,), L)
+            req.prefilled = offs + take
+            if req.prefilled < len(req.prompt):
+                return []
+            # prompt fully resident: the last chunk's logits are the first
+            # generated token, and the finished prefix becomes donatable
+            self.sched.finish_prefill(req)
+            req.out.append(tok)
+            obs.token_emitted(req.rid, t1)
+            if self.prefix is not None:
+                self.prefix.insert(req.prompt, req.slot, self.kv)
+            return self._retire([req])
 
-    def _run_decode(self) -> list:
-        t0 = self.obs.clock()
-        ecfg = self.ecfg
-        rows = np.asarray(
-            [self.kv.scratch_row(i) for i in range(ecfg.lanes)], np.int32
-        )
-        ids = np.zeros((ecfg.lanes, 1), np.int32)
-        pos = np.zeros((ecfg.lanes,), np.int32)
-        active = sorted(self.sched.running.items())
-        for lane, req in active:
-            rows[lane] = req.slot
-            ids[lane, 0] = req.out[-1]
-            pos[lane] = req.pos
-        next_ids, self.kv.pool = self._decode(
-            self.params, self.kv.pool, jnp.asarray(rows), jnp.asarray(ids),
-            jnp.asarray(pos),
-        )
-        next_ids = np.asarray(next_ids)  # device sync
-        t1 = self.obs.clock()
-        for lane, req in active:
-            req.out.append(int(next_ids[lane]))
-            req.pos += 1
-            self.obs.token_emitted(req.rid, t1)
-        self.obs.step_recorded(
-            "decode", tuple(r.rid for _, r in active), len(active), t0, t1,
-            lanes=ecfg.lanes,
-        )
-        return self._retire([r for _, r in active])
+    def _run_decode(self, st) -> list:
+        obs, ecfg = self.obs, self.ecfg
+        with obs.span("engine.schedule"):
+            active = sorted(self.sched.running.items())
+        with obs.span("engine.inputs"):
+            rows = np.asarray(
+                [self.kv.scratch_row(i) for i in range(ecfg.lanes)], np.int32
+            )
+            ids = np.zeros((ecfg.lanes, 1), np.int32)
+            pos = np.zeros((ecfg.lanes,), np.int32)
+            for lane, req in active:
+                rows[lane] = req.slot
+                ids[lane, 0] = req.out[-1]
+                pos[lane] = req.pos
+            args = (jnp.asarray(rows), jnp.asarray(ids), jnp.asarray(pos))
+        with obs.span("engine.dispatch"):
+            next_ids, self.kv.pool = self._decode(self.params, self.kv.pool,
+                                                  *args)
+        with obs.span("engine.sync"):
+            next_ids = np.asarray(next_ids)
+        with obs.span("engine.emit"):
+            t1 = st.record("decode", tuple(r.rid for _, r in active),
+                           len(active), lanes=ecfg.lanes)
+            for lane, req in active:
+                req.out.append(int(next_ids[lane]))
+                req.pos += 1
+                obs.token_emitted(req.rid, t1)
+            return self._retire([r for _, r in active])
 
     def _retire(self, reqs) -> list:
+        """Finish the requests that stop here; returns them."""
         done = []
         for req in reqs:
             reason = Scheduler.stop_reason(req, self.ecfg.page_len)

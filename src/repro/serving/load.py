@@ -156,8 +156,13 @@ def replay(engine, trace: list[LoadRequest], speed: float = 1.0,
     while i < len(trace) or engine.sched.has_work:
         now = (time.perf_counter() - t0) * speed
         while i < len(trace) and trace[i].t <= now:
-            rids.append(engine.add_request(list(trace[i].prompt),
-                                           max_new=trace[i].max_new))
+            # TTFT and queue wait start when the request was due, not
+            # when the step that was running then let it in: its delay
+            # carries over to the engine's clock
+            late = time.perf_counter() - t0 - trace[i].t / speed
+            rids.append(engine.add_request(
+                list(trace[i].prompt), max_new=trace[i].max_new,
+                arrival=engine.obs.clock() - late))
             i += 1
         if engine.sched.has_work:
             engine.step()

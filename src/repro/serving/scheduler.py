@@ -68,17 +68,6 @@ class Scheduler:
         self._free_lanes = list(range(lanes - 1, -1, -1))
         self._last = "idle"  # last planned action (chunked interleave)
 
-    def _gauges(self) -> None:
-        if self.obs is None or not self.obs.enabled:
-            return
-        reg = self.obs.registry
-        reg.gauge("serve_queue_depth", "waiting requests").set(
-            len(self.waiting)
-        )
-        reg.gauge("serve_active_lanes", "lanes decoding live work").set(
-            len(self.running)
-        )
-
     # ------------------------------------------------------------- queries
 
     @property
@@ -129,7 +118,6 @@ class Scheduler:
 
     def add(self, req: Request) -> None:
         self.waiting.append(req)
-        self._gauges()
 
     def admit(self, slot: int, step: int) -> Request:
         """Pop the next waiting request onto a free lane with KV slot
@@ -150,7 +138,6 @@ class Scheduler:
         req.slot = slot
         req.prefill_step = step
         self.prefilling = req
-        self._gauges()
         return req
 
     def finish_prefill(self, req: Request) -> None:
@@ -161,7 +148,6 @@ class Scheduler:
         req.pos = len(req.prompt)
         req.prefilled = len(req.prompt)
         self.running[req.lane] = req
-        self._gauges()
 
     def finish(self, req: Request, step: int) -> None:
         """Evict a completed request: frees the lane (the engine frees the
@@ -169,7 +155,6 @@ class Scheduler:
         req.finish_step = step
         del self.running[req.lane]
         self._free_lanes.append(req.lane)
-        self._gauges()
 
     @staticmethod
     def stop_reason(req: Request, page_len: int) -> str | None:

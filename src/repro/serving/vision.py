@@ -111,23 +111,31 @@ class VisionEngine:
 
     def classify_frame(self, image: jax.Array) -> int:
         """One frame [H, W, C] through the chip chain; returns the top-1
-        class and records the frame's stage traffic as a typed event."""
+        class and records the frame's stage traffic as a typed event.
+
+        The frame runs under a ``vision.frame`` span (carrying its id)
+        with phases ``vision.dispatch`` (input staging and the chip
+        chain's calls), ``vision.sync`` (the logits' readback) and
+        ``vision.emit``."""
         if self.runner is not None:
             return self._stream_pipelined(jnp.asarray(image)[None])[0]
-        t0 = self.obs.clock()
-        x = jnp.asarray(image)[None]  # fixed shape [1, H, W, C]
-        for fn, chip_params, _ in self._chain:
-            x = fn(chip_params, x)  # hidden handoff == inter-chip hop
-        logits = np.asarray(jax.device_get(x), np.float32)[0]
+        obs = self.obs
         fid = self._next_fid
         self._next_fid += 1
-        self.obs.step_recorded("frame", (fid,), self.cfg.seq_len,
-                               t0, self.obs.clock())
-        if self.obs.enabled:
-            self.obs.registry.counter(
-                "vision_frames_total", "frames streamed"
-            ).inc()
-        return int(logits.argmax())
+        with obs.step_span("vision.frame", frame=fid) as st:
+            with obs.span("vision.dispatch"):
+                x = jnp.asarray(image)[None]  # fixed shape [1, H, W, C]
+                for fn, chip_params, _ in self._chain:
+                    x = fn(chip_params, x)  # hidden handoff == inter-chip hop
+            with obs.span("vision.sync"):
+                logits = np.asarray(jax.device_get(x), np.float32)[0]
+            with obs.span("vision.emit"):
+                st.record("frame", (fid,), self.cfg.seq_len)
+                if obs.enabled:
+                    obs.registry.counter(
+                        "vision_frames_total", "frames streamed"
+                    ).inc()
+                return int(logits.argmax())
 
     @property
     def trace(self) -> list:
@@ -145,30 +153,32 @@ class VisionEngine:
         return [self.classify_frame(f) for f in frames]
 
     def _stream_pipelined(self, frames: jax.Array) -> list[int]:
+        """Slabs of ``runner.capacity`` frames, each under one
+        ``vision.frame`` span with the phases of :meth:`classify_frame`."""
+        obs = self.obs
         out: list[int] = []
         cap = self.runner.capacity
         for i in range(0, frames.shape[0], cap):
-            chunk = frames[i:i + cap]
-            t0 = self.obs.clock()
-            logits = jax.device_get(self.runner.forward({"images": chunk}))
-            t1 = self.obs.clock()
-            n = chunk.shape[0]
-            for j in range(n):
-                fid = self._next_fid
-                self._next_fid += 1
-                # bill each frame an equal slice of the slab wall so the
-                # derived trace keeps one event per frame
-                self.obs.step_recorded(
-                    "frame", (fid,), self.cfg.seq_len,
-                    t0 + (t1 - t0) * j / n, t0 + (t1 - t0) * (j + 1) / n,
-                )
-            if self.obs.enabled:
-                self.obs.registry.counter(
-                    "vision_frames_total", "frames streamed"
-                ).inc(n)
-            out.extend(
-                int(v.argmax()) for v in np.asarray(logits, np.float32)
-            )
+            n = min(cap, frames.shape[0] - i)
+            with obs.step_span("vision.frame", frame=self._next_fid,
+                               frames=n) as st:
+                with obs.span("vision.dispatch"):
+                    logits = self.runner.forward(
+                        {"images": frames[i:i + cap]})
+                with obs.span("vision.sync"):
+                    logits = np.asarray(jax.device_get(logits), np.float32)
+                with obs.span("vision.emit"):
+                    # one event per frame, so the derived trace keeps
+                    # them; the step bills each a slice of the slab wall
+                    for _ in range(n):
+                        st.record("frame", (self._next_fid,),
+                                  self.cfg.seq_len)
+                        self._next_fid += 1
+                    if obs.enabled:
+                        obs.registry.counter(
+                            "vision_frames_total", "frames streamed"
+                        ).inc(n)
+                    out.extend(int(v.argmax()) for v in logits)
         return out
 
     def measured_report(self, frames, reps: int = 3):

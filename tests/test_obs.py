@@ -3,9 +3,10 @@
 Crux checks: the Prometheus text exposition round-trips through the
 repo's own parser bit-for-bit in value space (the tier-1 exporter
 acceptance), histogram quantiles are sane under the fixed-bucket
-estimator, the span tracer derives the legacy trace view exactly, and
-``profiled_call`` distinguishes eager dispatches (wall captured under
-``profile=True``) from traced ones (counted only).
+estimator, the span tracer derives the legacy trace view exactly,
+``Obs.span`` nests step phases on its clock (and only annotates when
+disabled), and every kernel wrapper's named scope reaches the lowered
+program.
 """
 
 import math
@@ -236,41 +237,184 @@ def test_slo_no_samples_is_indeterminate_not_failing():
     assert res["pass"] is True
 
 
-# -------------------------------------------------------- kernel profiling
+# ------------------------------------------------------------ step spans
 
-def test_profiled_call_eager_capture():
-    o = obs.Obs(profile=True)
-    out = obs.profiled_call("k", o, lambda: jnp.ones((4,)) * 2)
-    assert float(out[0]) == 2.0
-    calls = o.registry.counter(
-        "kernel_calls_total", labels={"kernel": "k", "mode": "eager"}
-    )
-    assert calls.value == 1
-    wall = o.registry.histogram("kernel_wall_seconds",
-                                labels={"kernel": "k"})
-    assert wall.count == 1 and wall.sum > 0
+def _tick_clock(step=1.0):
+    t = [0.0]
+
+    def clock():
+        t[0] += step
+        return t[0]
+
+    return clock
 
 
-def test_profiled_call_traced_counts_only():
-    o = obs.Obs(profile=True)
+def test_span_nests_orders_and_attaches_phases_to_the_step():
+    o = obs.Obs(clock=_tick_clock())
+    with o.step_span("engine.decode", step=1) as st:  # t0=1
+        with o.span("engine.schedule"):             # 2..3
+            pass
+        with o.span("engine.sync"):                 # 4..5
+            pass
+        with o.span("engine.emit"):                 # 6..9
+            assert st.record("decode", (0,), 1) == 7.0
+            o.clock()                               # 8
+    [ev] = o.steps                                  # step closes at 10
+    assert ev.phases == (("engine.decode", 1.0, 10.0),
+                         ("engine.schedule", 2.0, 3.0),
+                         ("engine.sync", 4.0, 5.0),
+                         ("engine.emit", 6.0, 9.0))
+    # the step record runs from the span's start until its result was
+    # recorded; self time is the step less its sync child
+    assert (ev.t_start, ev.t_end) == (1.0, 7.0)
+    (_, t0, t1), sync = ev.phases[0], ev.phases[2]
+    assert (t1 - t0) - (sync[2] - sync[1]) == 8.0
+    h = o.registry.histogram("serve_step_phase_seconds",
+                             labels={"kind": "decode", "phase": "engine.emit"})
+    assert (h.count, h.sum) == (1, 3.0)
+    assert o.registry.histogram("serve_step_wall_seconds",
+                                labels={"kind": "decode"}).sum == 6.0
 
-    @jax.jit
-    def f(x):
-        return obs.profiled_call("k2", o, lambda: x * 2)
 
-    f(jnp.ones((4,)))
-    calls = o.registry.counter(
-        "kernel_calls_total", labels={"kernel": "k2", "mode": "traced"}
-    )
-    assert calls.value == 1
-    # no wall capture inside a trace: blocking a tracer is impossible
-    wall = o.registry.histogram("kernel_wall_seconds",
-                                labels={"kernel": "k2"})
-    assert wall.count == 0
+def test_span_without_a_step_inside_attaches_nothing():
+    o = obs.Obs(clock=_tick_clock())
+    # a phase outside a step is only an annotation: it reads no clock
+    with o.span("engine.sync"):
+        pass
+    assert o.clock() == 1.0
+    # a step that raised before its result was recorded keeps nothing
+    with pytest.raises(RuntimeError):
+        with o.step_span("engine.decode"):
+            with o.span("engine.sync"):
+                raise RuntimeError("device lost")
+    assert o.steps == [] and o.registry.families() == []
+    # the next step starts from an empty phase list
+    with o.step_span("engine.decode") as st:
+        st.record("decode", (0,), 1)
+    assert [n for n, _, _ in o.steps[0].phases] == ["engine.decode"]
 
 
-def test_profiled_call_without_obs_is_passthrough():
-    assert float(obs.profiled_call("k3", None, lambda: jnp.float32(7))) == 7
+def test_step_span_bills_a_slab_of_frames_equal_slices():
+    o = obs.Obs(clock=_tick_clock())
+    with o.step_span("vision.frame", frame=0, frames=3) as st:  # t0=1
+        with o.span("vision.sync"):                 # 2..3
+            pass
+        for fid in range(3):
+            st.record("frame", (fid,), 197)          # end stamped at 4
+    assert [(e.rids, e.t_start, e.t_end) for e in o.steps] == [
+        ((0,), 1.0, 2.0), ((1,), 2.0, 3.0), ((2,), 3.0, 4.0)]
+    # the phases ride on the slab's last frame
+    assert [len(e.phases) for e in o.steps] == [0, 0, 2]
+    assert o.registry.histogram("serve_step_wall_seconds",
+                                labels={"kind": "frame"}).count == 3
+
+
+def test_disabled_span_only_annotates(monkeypatch):
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **meta):
+            entered.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    for enabled in (False, True):
+        o = obs.Obs(enabled=enabled, clock=_tick_clock())
+        with o.step_span("vision.frame", frame=3) as st:
+            with o.span("vision.sync"):
+                st.record("frame", (3,), 197)
+        assert entered == [("vision.frame", {"frame": 3}),
+                           ("vision.sync", {})]
+        entered.clear()
+        # the step record is kept either way (the pipeline model's input)
+        [ev] = o.steps
+        assert ev.wall_s > 0
+        assert len(ev.phases) == (2 if enabled else 0)
+        assert bool(o.registry.families()) == enabled
+
+
+def test_slow_step_is_logged_with_its_phases():
+    import logging
+
+    class Keep(logging.Handler):
+        def __init__(self):
+            super().__init__()
+            self.lines = []
+
+        def emit(self, record):
+            self.lines.append(record.getMessage())
+
+    keep = Keep()
+    logger = logging.getLogger("repro.obs")
+    logger.addHandler(keep)
+    try:
+        # a compiling warm-up step first, then the steady steps; then
+        # steps of microseconds, whose outliers are no stall
+        t = [0.0]
+        for walls in ([3.0] + [0.1] * 16 + [0.3, 2.0, 1.5],
+                      [1e-4] * 16 + [5e-3]):
+            o = obs.Obs(clock=lambda: t[0])
+            for w in walls:
+                with o.step_span("engine.decode") as st:
+                    with o.span("engine.sync"):
+                        t[0] += w
+                    with o.span("engine.emit"):
+                        st.record("decode", (0,), 1)
+    finally:
+        logger.removeHandler(keep)
+    # every step over 5x the median of its kind, however slow the
+    # warm-up or an earlier stall was
+    assert len(keep.lines) == 2
+    for line, wall in zip(keep.lines, ("2.0000", "1.5000")):
+        assert line.startswith(f"slow step engine.decode: {wall} s")
+        assert f"engine.sync={float(wall):.4g}" in line
+
+
+# ------------------------------------------------- kernel named scopes
+
+def _scoped_kernels():
+    from repro.core import cim as cimlib
+    from repro.core import mx as mxlib
+    from repro.kernels.cim_linear import ops as cim_ops
+    from repro.kernels.flash_attention import ops as fa_ops
+    from repro.kernels.mxfp4_matmul import ops as mm_ops
+    from repro.kernels.paged_attention import ops as pa_ops
+
+    x = jnp.ones((16, 64), jnp.float32)
+    wq = mxlib.quantize_w(jnp.ones((64, 32), jnp.float32))
+    cfg = cimlib.CIMConfig()
+    calib = cimlib.calibrate_rowhist([x], wq, cfg)
+    codes = mxlib.pack_codes(wq.codes.T).T
+    exps = mxlib.exps_to_biased(wq.exps)
+    q = jnp.ones((1, 8, 2, 16), jnp.float32)
+    pages = jnp.ones((2, 16, 4, 16), jnp.float32)
+    lanes = jnp.zeros((2,), jnp.int32)
+    return {
+        "mxfp4_matmul": lambda: mm_ops.mxfp4_matmul(
+            x.astype(jnp.bfloat16), codes, exps, interpret=True),
+        "cim_linear": lambda: cim_ops.cim_linear(
+            x, wq, calib, cfg=cfg, interpret=True),
+        "flash_attention": lambda: fa_ops.flash_attention(
+            q, q, q, interpret=True),
+        "paged_attention.ref": lambda: pa_ops.ragged_paged_decode(
+            jnp.ones((2, 2, 1, 16), jnp.float32), lanes, lanes + 3,
+            kv=pages, scale=0.25),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["mxfp4_matmul", "cim_linear",
+                                    "flash_attention", "paged_attention.ref"])
+def test_kernel_named_scope_reaches_hlo_metadata(kernel):
+    """The wrappers' ``repro/<kernel>`` scopes cost nothing at run time
+    and name the kernel's ops in the lowered program's locations."""
+    text = jax.jit(_scoped_kernels()[kernel]).lower().as_text(
+        debug_info=True)
+    assert f"/repro/{kernel}/" in text
 
 
 # ------------------------------------------------------------- fidelity

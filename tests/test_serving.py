@@ -700,3 +700,59 @@ def test_engine_disabled_obs_matches_default_trace(float_model):
     assert eng_off.obs.registry.families() == []
     assert eng_off.obs.finished == []
     assert len(eng_on.obs.finished) == 1
+
+
+ENGINE_PHASES = ("engine.schedule", "engine.inputs", "engine.dispatch",
+                 "engine.sync", "engine.emit")
+
+
+@pytest.mark.parametrize("policy", ["prefill", "chunked", "decode"])
+def test_engine_steps_carry_their_phases(float_model, policy):
+    """Every step records its span (named for its kind) and the five
+    phases in order, inside its bounds; the step record's own bounds run
+    from the span's start to the recording of its result, as the emit
+    phase opens after the sync."""
+    params, ctx = float_model
+    chunk = 4 if policy == "chunked" else None
+    ecfg = EngineConfig(lanes=2, num_slots=3, page_len=24, prefill_len=8,
+                        policy=policy, chunk_len=chunk)
+    eng = Engine(params, CFG, ctx, ecfg)
+    for n in (6, 3, 7):
+        eng.add_request(list(range(1, n + 1)), max_new=3)
+        eng.step()
+    eng.run()
+    prefill = "engine.chunk" if chunk else "engine.prefill"
+    want = {"prefill": prefill, "decode": "engine.decode"}
+    names = set()
+    for ev in eng.obs.steps:
+        (name, lo, hi), *inner = ev.phases
+        names.add(name)
+        assert name == want[ev.kind]
+        assert tuple(n for n, _, _ in inner) == ENGINE_PHASES
+        ends = [lo] + [t for _, a, b in inner for t in (a, b)] + [hi]
+        assert ends == sorted(ends)
+        assert ev.t_start == pytest.approx(lo, abs=1e-3)
+        assert ev.t_end == pytest.approx(inner[3][2], abs=1e-3)
+        assert inner[4][1] <= ev.t_end <= inner[4][2]
+    assert names == {prefill, "engine.decode"}
+    reg = eng.obs.registry
+    n_dec = sum(e.kind == "decode" for e in eng.obs.steps)
+    assert reg.histogram("serve_step_phase_seconds", labels={
+        "kind": "decode", "phase": "engine.sync"}).count == n_dec
+
+
+def test_add_request_arrival_starts_ttft_and_queue_wait(float_model):
+    params, ctx = float_model
+    ecfg = EngineConfig(lanes=2, num_slots=2, page_len=16, prefill_len=8)
+    eng = Engine(params, CFG, ctx, ecfg)
+    due = eng.obs.clock() - 0.5  # came due half a second ago
+    late = eng.add_request([3, 1, 4], max_new=2, arrival=due)
+    now = eng.add_request([1, 5, 9], max_new=2)
+    eng.run()
+    spans = {r.rid: r for r in eng.obs.finished}
+    r = spans[late]
+    assert r.t_enqueue == due
+    assert r.queue_wait_s == r.t_admitted - due > 0.5
+    assert r.ttft_s == r.t_first_token - due > 0.5
+    # without an arrival the span starts when the request was added
+    assert spans[now].t_enqueue >= due + 0.5

@@ -313,6 +313,23 @@ def _streamed_engine(workload, n_frames=3, chips=None):
     return eng
 
 
+def test_frames_carry_their_phases():
+    """Each frame records its ``vision.frame`` span and its three phases
+    in order; the frame's record ends where its readback ends."""
+    eng = _streamed_engine("vit-b16")
+    for ev in eng.obs.steps:
+        (name, lo, hi), *inner = ev.phases
+        assert name == "vision.frame"
+        assert [n for n, _, _ in inner] == ["vision.dispatch", "vision.sync",
+                                            "vision.emit"]
+        ends = [lo] + [t for _, a, b in inner for t in (a, b)] + [hi]
+        assert ends == sorted(ends)
+        assert ev.t_end == pytest.approx(inner[1][2], abs=1e-3)
+    h = eng.obs.registry.histogram("serve_step_phase_seconds", labels={
+        "kind": "frame", "phase": "vision.sync"})
+    assert h.count == len(eng.obs.steps) == 3
+
+
 def test_vit_b16_measured_traffic_matches_table7():
     """Acceptance: steady-state FPS from the engine's measured traffic
     matches PAPER_TABLE7 within 5% for vit-b16 (single chip)."""
