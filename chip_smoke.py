@@ -153,7 +153,6 @@ def check_analog_linears(cfg, params, ctx, ref_ctx, frame):
     from repro.core import cim as cimlib
     from repro.core import mx as mxlib
     from repro.kernels.cim_linear import ops as cim_ops
-    from repro.kernels.mxfp4_matmul import ops as mm_ops
     from repro.layers import backends
     from repro.models import calibrate, vit
     from repro.obs import sqnr_db
@@ -188,8 +187,8 @@ def check_analog_linears(cfg, params, ctx, ref_ctx, frame):
     check(sum(t[0] for t in tiles.values()) == len(caps),
           "a captured linear of vit-b16 is not analog")
     for (k, n), (count, sq, diff, size) in sorted(tiles.items()):
-        log(f"vit-b16 cim: {k}->{n} x{count} (bk={mm_ops.pick_bk(k)},"
-            f" bn={mm_ops.pick_bn(n)}), kernel vs jnp on teacher-forced "
+        log(f"vit-b16 cim: {k}->{n} x{count} (bk={cim_ops.pick_bk(k)},"
+            f" bn={cim_ops.pick_bn(n)}), kernel vs jnp on teacher-forced "
             f"inputs: min SQNR {sq:.2f} dB, {diff}/{size} outputs differ "
             f"(bound {LINEAR_SQNR_MIN_DB} dB)")
     caps_pl, eager_pl = capture(ctx)

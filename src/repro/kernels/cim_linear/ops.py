@@ -10,12 +10,31 @@ import jax.numpy as jnp
 from repro.core import cim as cimlib
 from repro.core import mx as mxlib
 from repro.kernels.cim_linear.kernel import cim_linear_kernel
-from repro.kernels.mxfp4_matmul.ops import (
-    _round_up,
-    pick_bk,
-    pick_bm,
-    pick_bn,
-)
+from repro.kernels.mxfp4_matmul.ops import _round_up, pick_bm
+
+
+def pick_bn(n: int) -> int:
+    """N tile: 128 lanes where they divide ``n``, else the whole of ``n``
+    (Mosaic needs a block's last dim to be a multiple of 128 or the whole
+    axis; padding N would copy the resident weights every call). ViT's
+    1000-class head is one tile."""
+    return 128 if n % 128 == 0 else n
+
+
+BK_MAX = 512
+
+
+def pick_bk(k: int) -> int:
+    """K tile. The exponent operand's block is ``(bk // 32, bn)``, and
+    Mosaic needs its rows to be a multiple of 8 or the whole ``k // 32``
+    axis: so ``bk`` is the largest multiple of 256 that divides ``k``, at
+    most ``BK_MAX`` (the tile holds the ADC emulation's intermediates),
+    and the whole of ``k`` where none does (gemma3's 1152)."""
+    step = 8 * 32
+    for bk in range(min(BK_MAX, k) // step * step, 0, -step):
+        if k % bk == 0:
+            return bk
+    return k
 
 
 def cim_linear(

@@ -218,8 +218,11 @@ class _MXFP4WeightOnly(LinearBackend):
         if ctx.use_pallas:
             from repro.kernels.mxfp4_matmul import ops as mmops
 
+            # bf16 activations, as on the jnp path below (the ViT's patch
+            # embedding is the one caller that hands over f32 pixels)
             return mmops.mxfp4_matmul(
-                x, params["codes"], params["exps"], interpret=ctx.interpret,
+                x.astype(jnp.bfloat16), params["codes"], params["exps"],
+                interpret=ctx.interpret,
             )
         w = _dequant_packed(params["codes"], params["exps"])
         return jnp.matmul(x.astype(jnp.bfloat16), w)

@@ -4,8 +4,9 @@ Nothing runs: each kernel is lowered with ``interpret=False`` for a chip
 that is described, not attached, so the TPU compiler refuses here what it
 would refuse on the chip (block tilings, vector layouts, accumulator
 types) — faults that interpret mode hides. Shapes come from the published
-configurations: ViT-B/16's M=197 linears and head, gemma3-1b's MLP, and the paged
-decode pools of gemma3-1b and h2o-danube.
+configurations: ViT-B/16's M=197 linears and head, gemma3-1b's MLP,
+h2o-danube-1.8b's five linears at its decode and prefill rows, and the
+paged decode pools of gemma3-1b and h2o-danube.
 """
 
 import os
@@ -52,6 +53,7 @@ def _compiles_to_kernel(one_chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    return hlo
 
 
 VIT = C.VISION_ARCHS["vit-b16"]
@@ -64,6 +66,16 @@ VIT_LINEARS = [(VIT.seq_len, VIT.d_model, 3 * VIT.d_model),  # 768 -> 2304
 GEMMA_MLP = [(m, k, n) for m in (LANES, 200)  # decode and prefill rows
              for k, n in ((GEMMA.d_model, GEMMA.d_ff),  # 1152 -> 6912
                           (GEMMA.d_ff, GEMMA.d_model))]  # 6912 -> 1152
+# q and o (2560 -> 2560), k and v (2560 -> 640), gate and up
+# (2560 -> 6912), down (6912 -> 2560), head (2560 -> 32000); at the decode
+# rows of 12 and 32 lanes, the 256-token prefill and the 512-token chunk
+DANUBE_LINEARS = [
+    (m, k, n) for m in (12, 32, 256, 512)
+    for k, n in ((DANUBE.d_model, DANUBE.n_heads * DANUBE.hd),
+                 (DANUBE.d_model, DANUBE.n_kv_heads * DANUBE.hd),
+                 (DANUBE.d_model, DANUBE.d_ff),
+                 (DANUBE.d_ff, DANUBE.d_model),
+                 (DANUBE.d_model, DANUBE.vocab_size))]
 
 
 @pytest.mark.parametrize("m,k,n", VIT_LINEARS)
@@ -80,14 +92,20 @@ def test_cim_linear_compiles(one_chip, m, k, n):
     )
 
 
-@pytest.mark.parametrize("m,k,n", VIT_LINEARS + GEMMA_MLP)
+@pytest.mark.parametrize("m,k,n", VIT_LINEARS + GEMMA_MLP + DANUBE_LINEARS)
 def test_mxfp4_matmul_compiles(one_chip, m, k, n):
-    _compiles_to_kernel(
+    """One kernel per linear, named for the kernel: the benchmark's
+    roofline shares divide by one ``mxfp4_matmul`` event per call."""
+    hlo = _compiles_to_kernel(
         one_chip,
         lambda x, c, e: mm_ops.mxfp4_matmul(x, c, e, interpret=False),
         ((m, k), jnp.bfloat16), ((k // 2, n), jnp.uint8),
         ((k // 32, n), jnp.uint8),
     )
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert "mxfp4_matmul" in calls[0].split("=", 1)[0]
 
 
 def _pool(cfg, init):
